@@ -19,6 +19,7 @@ from benchmarks.paper_tables import ALL as PAPER          # noqa: E402
 from benchmarks.kernel_bench import ALL as KERNELS        # noqa: E402
 from benchmarks import swap_bench                         # noqa: E402
 from benchmarks.swap_bench import ALL as SWAP             # noqa: E402
+from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def roofline_rows():
@@ -43,6 +44,7 @@ def main() -> None:
                     help="where to write BENCH_swap.json when swap benches "
                          "run (default: results/BENCH_swap.json)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     benches = dict(PAPER)
     benches.update(KERNELS)

@@ -7,10 +7,10 @@ One interpreter, two realisations of its transfer ops:
   bit-for-bit the accounting the planner validation suite gates on;
 * :class:`AsyncDeviceBackend` (``"async"``) — every ``SwapOut`` /
   ``Prefetch`` op is issued as a real ``jax.device_put`` against the
-  device's (pinned) host memory space, *dispatched* at its scheduled EO
+  device's ``pinned_host`` memory space, *dispatched* at its scheduled EO
   and fenced only when the consumer computes, so DMA overlaps the compute
   in between (the ROADMAP "async double-buffer on real device streams"
-  item).  Swap-outs donate their device buffer.  The backend measures
+  item).  The backend measures
   ``inflight_high_water`` (achieved double-buffer occupancy) and the
   achieved-overlap fraction against the plan's
   ``peak_inflight_prefetch`` — see :meth:`AsyncDeviceBackend.report`.
@@ -61,7 +61,7 @@ from repro.core.exec.layers import (_needs_deriv, _param_owner,
                                     loss_derivative, loss_forward)
 from repro.core.exec.store import (ActivationStore, DeviceStreamEngine,
                                    HbmTracker, SwapExecStats, SyncHostEngine,
-                                   TransferEngine)
+                                   TransferEngine, commit)
 from repro.core.execution_order import OrderedTensors, compute_execution_order
 from repro.core.graph import LOSS_KINDS, WEIGHTED_KINDS, LayerGraph
 from repro.core.offload import OffloadSchedule
@@ -283,6 +283,8 @@ class ScheduleCursor:
             1 for d in schedule.decisions if d.inplace)
         self.hbm = HbmTracker()
         self.store = ActivationStore(ordered, self.hbm, engine=engine)
+        params, x, label, mask = commit((params, x, label, mask),
+                                        getattr(engine, "device", None))
         self.store.device["__input__"] = x
         self.env = _ComputeEnv(graph, params, label, mask,
                                get=lambda n: self.store.get(n, self.stats),
@@ -578,14 +580,13 @@ class SimulatedBackend(_ReplayBackend):
 class AsyncDeviceBackend(_ReplayBackend):
     """Issue the compiled transfer ops on real device streams.
 
-    ``SwapOut`` lowers to ``jax.device_put(arr, <host memory>, donate=True)``
+    ``SwapOut`` lowers to ``jax.device_put(arr, <pinned_host>)``
     dispatched (not awaited) during its scheduled phase; ``Prefetch``
     lowers to the host->device put issued ``prefetch_margin`` phases ahead
     of the read and fenced only when the consuming compute actually touches
-    the tensor.  On platforms with a ``pinned_host`` memory space (TPU,
-    GPU) the copies are genuine DMA against pinned memory; on CPU the
-    ``unpinned_host`` space keeps the same dispatch/fence structure for
-    testing.  ``report()`` carries the achieved overlap."""
+    the tensor.  The device's ``pinned_host`` memory space is required (a
+    device without one raises); on a TPU the copies are DMA against pinned
+    memory.  ``report()`` carries the achieved overlap."""
 
     name = "async"
 
@@ -807,6 +808,8 @@ class JitBlocksBackend(AsyncDeviceBackend):
         engine = self.make_engine()
         hbm = HbmTracker()
         store = ActivationStore(ordered, hbm, engine=engine)
+        params, x, label, mask = commit((params, x, label, mask),
+                                        engine.device)
         store.device["__input__"] = x
         env = _ComputeEnv(graph, params, label, mask,
                           get=lambda n: store.get(n, stats),

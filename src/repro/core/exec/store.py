@@ -206,27 +206,41 @@ class SyncHostEngine:
         pass
 
 
-def _host_memory_kind(device) -> Optional[str]:
-    """The device's host memory space: pinned when the platform has one
-    (TPU/GPU), the unpinned host space otherwise (CPU), None when the
-    installed jax predates memory kinds."""
-    try:
-        kinds = {m.kind for m in device.addressable_memories()}
-    except Exception:  # pragma: no cover - very old jax
-        return None
-    if "pinned_host" in kinds:
-        return "pinned_host"
-    if "unpinned_host" in kinds:
-        return "unpinned_host"
-    return None
+def commit(tree, device=None):
+    """``tree`` with every array committed to ``device`` (default: the
+    first device); arrays already there are not copied.
+
+    JAX keys its compile cache on whether each argument is committed to a
+    device, and a ``device_put`` transfer always returns a committed
+    array.  Params that are uncommitted in the first step (fresh from
+    init) and committed in the next (produced from transferred arrays)
+    would make every op compile again; committing the inputs of a step up
+    front keeps later steps on the programs the first one compiled."""
+    device = device if device is not None else jax.devices()[0]
+    return jax.tree_util.tree_map(lambda a: jax.device_put(a, device), tree)
+
+
+def _host_memory_kind(device) -> str:
+    """The device's pinned host memory space, where swapped activations
+    live.  A device without one has no host tier to swap to, so this
+    raises instead of handing back a "host" sharding that is really HBM."""
+    kinds = {m.kind for m in device.addressable_memories()}
+    if "pinned_host" not in kinds:
+        raise RuntimeError(
+            f"device {device} has no pinned_host memory space "
+            f"(memory kinds: {sorted(kinds)}); swapped activations would "
+            "never leave device memory")
+    return "pinned_host"
 
 
 class DeviceStreamEngine:
     """Async device-stream transfers via ``jax.device_put``.
 
-    Swap-outs are dispatched as donated device->host copies the moment
-    their op is replayed — donation releases the device buffer without a
-    blocking copy-back.  Prefetches are dispatched host->device at their
+    Swap-outs are dispatched as device->host copies into ``pinned_host``
+    the moment their op is replayed.  The copies are not donated (JAX
+    refuses donation across memory kinds); the store drops its reference
+    to the device array, so the runtime frees the buffer once the copy
+    has read it.  Prefetches are dispatched host->device at their
     scheduled EO and left *in flight*; the consumer's read fences them.
     JAX's runtime orders a prefetch after its own swap-out automatically
     (data dependency), so no manual event chaining is needed.
@@ -270,8 +284,7 @@ class DeviceStreamEngine:
         kind = _host_memory_kind(self.device)
         Single = jax.sharding.SingleDeviceSharding
         self.device_sharding = Single(self.device)
-        self.host_sharding = (Single(self.device, memory_kind=kind)
-                              if kind else Single(self.device))
+        self.host_sharding = Single(self.device, memory_kind=kind)
         self.host_memory_kind = kind
         # owner -> (nbytes, arrays, issue timestamp, emulated ready time)
         self._inflight: Dict[str, Tuple[int, List[jax.Array], float,
@@ -313,7 +326,7 @@ class DeviceStreamEngine:
                  nbytes: int) -> Dict[str, Any]:
         out = {}
         for m, a in members.items():
-            out[m] = jax.device_put(a, self.host_sharding, donate=True)
+            out[m] = jax.device_put(a, self.host_sharding)
             self.d2h_issued += 1
         # the d2h copy occupies the emulated bus too; its cost surfaces
         # through the completion times of the transfers queued behind it
@@ -426,7 +439,8 @@ class SessionScopedEngine:
     def __init__(self, inner: DeviceStreamEngine, scope: str):
         self.inner = inner
         self.scope = scope
-        self.host_memory_kind = getattr(inner, "host_memory_kind", None)
+        self.device = inner.device
+        self.host_memory_kind = inner.host_memory_kind
         self._sizes: Dict[str, int] = {}       # outstanding owner -> bytes
         self._opt_sizes: Dict[str, int] = {}
         self.inflight_bytes = 0

@@ -60,7 +60,6 @@ Knobs (all on :func:`plan_offload`):
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import List, Optional, Sequence, Tuple
 
 import jax
@@ -204,41 +203,21 @@ def plan_offload(ordered: OrderedTensors, *, min_idle_phases: int = 4,
 
 
 def offload_lowering() -> str:
-    """How offload decisions lower on the installed JAX.
-
-    ``"native"`` — ``save_and_offload_only_these_names`` exists, so
-    offloaded intermediates really move to pinned host memory.
-    ``"fallback_save"`` — the policy degrades to plain on-device saves:
-    the plan's DMA prices are moot and the HBM budget WILL be exceeded by
-    the offloaded bytes.  Recorded in ``CompiledMemoryPlan.report()`` so
-    the degradation is visible, not silent.
-    """
-    return ("native"
-            if hasattr(jax.checkpoint_policies,
-                       "save_and_offload_only_these_names")
-            else "fallback_save")
+    """How offload decisions lower: ``"native"`` — through
+    ``jax.checkpoint_policies.save_and_offload_only_these_names``, so
+    offloaded intermediates really move to pinned host memory.  Recorded
+    in ``CompiledMemoryPlan.report()``."""
+    return "native"
 
 
 def offload_policy(names: Sequence[str], *, saved: Sequence[str] = ()):
-    """jax.checkpoint policy offloading ``names`` to host memory.
+    """jax.checkpoint policy offloading ``names`` to pinned host memory.
 
     ``saved`` names are kept on device (no offload, no recompute) — the
-    remat planner's on-device keep set.  Falls back to plain save when the
-    offload policy is unavailable in the installed JAX; the fallback keeps
-    the offloaded names *resident*, so it warns that the planned HBM budget
-    no longer holds (see :func:`offload_lowering`).
+    remat planner's on-device keep set.
     """
-    cp = jax.checkpoint_policies
-    if offload_lowering() == "native":
-        return cp.save_and_offload_only_these_names(
-            names_which_can_be_saved=list(saved),
-            names_which_can_be_offloaded=list(names),
-            offload_src="device", offload_dst="pinned_host",
-        )
-    warnings.warn(
-        "jax.checkpoint_policies.save_and_offload_only_these_names is "
-        "unavailable in this JAX: offload decisions lower to plain saves, "
-        "so the offloaded intermediates stay resident and the planned HBM "
-        "budget will be exceeded (report()['offload_lowering'] == "
-        "'fallback_save')", RuntimeWarning, stacklevel=2)
-    return cp.save_only_these_names(*list(saved) + list(names))
+    return jax.checkpoint_policies.save_and_offload_only_these_names(
+        names_which_can_be_saved=list(saved),
+        names_which_can_be_offloaded=list(names),
+        offload_src="device", offload_dst="pinned_host",
+    )

@@ -308,6 +308,8 @@ class OptimRuntime:
                  lr: float = 3e-4, b1: float = 0.9, b2: float = 0.95,
                  eps: float = 1e-8, weight_decay: float = 0.1):
         import jax.numpy as jnp
+
+        from repro.core.exec.store import commit
         from repro.optim.compression import _deq, _q
 
         self.plan = plan
@@ -337,6 +339,10 @@ class OptimRuntime:
                 self.residual[s.layer] = enc - _deq(q, scale, enc.shape)
             else:
                 self.host_state[s.layer] = zero
+        # committed like the params and grads offloaded_update receives,
+        # so the second step reuses the first step's programs
+        self.host_state = commit(self.host_state)
+        self.residual = commit(self.residual)
 
     # --------------------------------------------------------- quant space
     # The m half quantizes linearly (signed, roughly normal — the int8
@@ -432,6 +438,10 @@ def offloaded_update(runtime: OptimRuntime, params, grads, stats=None):
     """
     import jax.numpy as jnp
 
+    from repro.core.exec.store import commit
+
+    # fresh init params are uncommitted, later ones are committed outputs
+    params, grads = commit((params, grads))
     runtime.count += 1
     t = float(runtime.count)
     c1 = 1.0 - runtime.b1 ** t

@@ -701,9 +701,6 @@ class CompiledMemoryPlan:
             out["offload_dma_bytes_per_layer"] = rp.offload_dma_bytes_per_layer
             out["est_step_time_s_per_layer"] = rp.est_step_time_s_per_layer
             if rp.offloaded:
-                # how the offload decisions actually lower on this JAX:
-                # "fallback_save" means the policy degrades to plain saves
-                # and the planned HBM budget will be exceeded
                 out["offload_lowering"] = offload_lowering()
         return out
 

@@ -3,7 +3,8 @@
 Differentiable: forward runs the Pallas kernel; backward recomputes via the
 blockwise-jnp formulation's VJP (flash-style recompute — no O(S^2) residual
 is ever stored, matching the paper's ethos of trading recompute for
-memory).  On non-TPU backends the kernel runs in interpret mode.
+memory).  The kernel runs compiled on a TPU and in interpret mode on the
+CPU (see :func:`repro.kernels.interpret_mode`).
 """
 
 from __future__ import annotations
@@ -13,20 +14,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash(q, k, v, causal, block_q, block_kv):
     return flash_attention_fwd(q, k, v, causal=causal, block_q=block_q,
-                               block_kv=block_kv, interpret=not _on_tpu())
+                               block_kv=block_kv, interpret=interpret_mode())
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_kv):

@@ -27,17 +27,19 @@ from jax.experimental import pallas as pl
 def _mlstm_chunk_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref,
                         y_ref, ni_ref, mi_ref, st_ref, nr_ref,
                         clf_ref, mst_ref, *, sm_scale: float):
-    q = q_ref[0, 0, :, 0].astype(jnp.float32) * sm_scale   # (Q,P)
-    k = k_ref[0, 0, :, 0].astype(jnp.float32)              # (Q,P)
-    v = v_ref[0, 0, :, 0].astype(jnp.float32)              # (Q,P)
-    li = li_ref[0, 0].astype(jnp.float32)                  # (Q,1)
-    lf = lf_ref[0, 0].astype(jnp.float32)                  # (Q,1)
+    q = q_ref[0, 0, 0].astype(jnp.float32) * sm_scale      # (Q,P)
+    k = k_ref[0, 0, 0].astype(jnp.float32)                 # (Q,P)
+    v = v_ref[0, 0, 0].astype(jnp.float32)                 # (Q,P)
+    li = li_ref[0, 0, 0].astype(jnp.float32)               # (Q,1)
+    lf = lf_ref[0, 0, 0].astype(jnp.float32)               # (Q,1)
 
     qq = q.shape[0]
-    lf_cum = jnp.cumsum(lf, axis=0)                        # (Q,1)
-    dmat = lf_cum - lf_cum.reshape(1, qq) + li.reshape(1, qq)
     tri = (jax.lax.broadcasted_iota(jnp.int32, (qq, qq), 0)
            >= jax.lax.broadcasted_iota(jnp.int32, (qq, qq), 1))
+    # cumsum as a masked row sum (Mosaic has no cumsum lowering)
+    lf_cum = jnp.sum(jnp.where(tri, lf.reshape(1, qq), 0.0), axis=1,
+                     keepdims=True)                        # (Q,1)
+    dmat = lf_cum - lf_cum.reshape(1, qq) + li.reshape(1, qq)
     dmat = jnp.where(tri, dmat, -1e30)
     m_intra = jnp.max(dmat, axis=1, keepdims=True)         # (Q,1)
     w = jnp.exp(dmat - m_intra)
@@ -47,9 +49,10 @@ def _mlstm_chunk_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref,
     sw = scores * w
     y = jax.lax.dot_general(sw, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    y_ref[0, 0, :, 0] = y.astype(y_ref.dtype)
-    ni_ref[0, 0] = jnp.sum(sw, axis=1, keepdims=True).astype(ni_ref.dtype)
-    mi_ref[0, 0] = m_intra.astype(mi_ref.dtype)
+    y_ref[0, 0, 0] = y.astype(y_ref.dtype)
+    ni_ref[0, 0, 0] = jnp.sum(sw, axis=1,
+                              keepdims=True).astype(ni_ref.dtype)
+    mi_ref[0, 0, 0] = m_intra.astype(mi_ref.dtype)
 
     decay_end = lf_cum[qq - 1] - lf_cum + li               # (Q,1)
     m_state = jnp.max(decay_end).reshape(1, 1)
@@ -57,9 +60,10 @@ def _mlstm_chunk_kernel(q_ref, k_ref, v_ref, li_ref, lf_ref,
     st = jax.lax.dot_general(k, v * sk, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)  # (P,P)
     st_ref[0, 0] = st.astype(st_ref.dtype)
-    nr_ref[0, 0] = jnp.sum(k * sk, axis=0).astype(nr_ref.dtype)
-    clf_ref[...] = lf_cum[qq - 1].reshape(1, 1).astype(clf_ref.dtype)
-    mst_ref[...] = m_state.astype(mst_ref.dtype)
+    nr_ref[0, 0] = jnp.sum(k * sk, axis=0,
+                           keepdims=True).astype(nr_ref.dtype)
+    clf_ref[0, 0] = lf_cum[qq - 1].reshape(1, 1).astype(clf_ref.dtype)
+    mst_ref[0, 0] = m_state.astype(mst_ref.dtype)
 
 
 def mlstm_chunk_pallas(q, k, v, li, lf, *, sm_scale: float,
@@ -73,39 +77,37 @@ def mlstm_chunk_pallas(q, k, v, li, lf, *, sm_scale: float,
     """
     import functools
     b, nc, qq, h, p = q.shape
+    # head-major tiles: the TPU lowering needs each block's last two dims
+    # to be (8, 128)-aligned or whole, so the head index must not sit in
+    # them — q/k/v as (b,nc,h,Q,P), gates as (b,nc,h,Q,1)
+    seq5 = lambda t: t.transpose(0, 1, 3, 2, 4)
+    gate5 = lambda t: t.transpose(0, 1, 3, 2)[..., None]
     grid = (b, nc, h)
     kern = functools.partial(_mlstm_chunk_kernel, sm_scale=sm_scale)
+    tile = lambda last: pl.BlockSpec((1, 1, 1, qq, last),
+                                     lambda bi, ci, hi: (bi, ci, hi, 0, 0))
+    per_head = lambda r, c: pl.BlockSpec(
+        (1, 1, r, c), lambda bi, ci, hi: (bi, ci * h + hi, 0, 0))
+    f32 = jnp.float32
     outs = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, qq, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, qq, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, qq, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, qq, 1), lambda bi, ci, hi: (bi, ci, 0, hi)),
-            pl.BlockSpec((1, 1, qq, 1), lambda bi, ci, hi: (bi, ci, 0, hi)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, qq, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, qq, 1), lambda bi, ci, hi: (bi, ci, 0, hi)),
-            pl.BlockSpec((1, 1, qq, 1), lambda bi, ci, hi: (bi, ci, 0, hi)),
-            pl.BlockSpec((1, 1, p, p), lambda bi, ci, hi: (bi, ci * h + hi, 0, 0)),
-            pl.BlockSpec((1, 1, p), lambda bi, ci, hi: (bi, ci * h + hi, 0)),
-            pl.BlockSpec((1, 1), lambda bi, ci, hi: (bi, ci * h + hi)),
-            pl.BlockSpec((1, 1), lambda bi, ci, hi: (bi, ci * h + hi)),
-        ],
+        in_specs=[tile(p), tile(p), tile(p), tile(1), tile(1)],
+        out_specs=[tile(p), tile(1), tile(1), per_head(p, p),
+                   per_head(1, p), per_head(1, 1), per_head(1, 1)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, nc, qq, h, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc, qq, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc, qq, h), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc * h, p, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc * h, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc * h), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc * h), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, h, qq, p), f32),
+            jax.ShapeDtypeStruct((b, nc, h, qq, 1), f32),
+            jax.ShapeDtypeStruct((b, nc, h, qq, 1), f32),
+            jax.ShapeDtypeStruct((b, nc * h, p, p), f32),
+            jax.ShapeDtypeStruct((b, nc * h, 1, p), f32),
+            jax.ShapeDtypeStruct((b, nc * h, 1, 1), f32),
+            jax.ShapeDtypeStruct((b, nc * h, 1, 1), f32),
         ],
         interpret=interpret,
-    )(q, k, v, li, lf)
+    )(seq5(q), seq5(k), seq5(v), gate5(li), gate5(lf))
     y, ni, mi, st, nr, clf, mst = outs
-    return (y, ni, mi,
+    back = lambda t: t[..., 0].transpose(0, 1, 3, 2)
+    return (y.transpose(0, 1, 3, 2, 4), back(ni), back(mi),
             st.reshape(b, nc, h, p, p), nr.reshape(b, nc, h, p),
             clf.reshape(b, nc, h), mst.reshape(b, nc, h))

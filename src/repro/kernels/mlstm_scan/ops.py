@@ -9,14 +9,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.mlstm_scan.kernel import mlstm_chunk_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -24,7 +18,7 @@ def mlstm_scan(q, k, v, i_gate, f_gate, *, chunk: int = 256,
                interpret: bool | None = None):
     """q,k,v: (b,s,h,p); i_gate,f_gate: (b,s,h) raw logits -> (b,s,h,p)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, s, h, p = q.shape
     scale = 1.0 / math.sqrt(p)
     lf = jax.nn.log_sigmoid(f_gate.astype(jnp.float32))

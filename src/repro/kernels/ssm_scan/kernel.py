@@ -28,19 +28,21 @@ from jax.experimental import pallas as pl
 
 def _ssd_chunk_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref,
                       y_ref, st_ref, clf_ref):
-    x = x_ref[0, 0, :, 0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)              # (Q, 1)
+    x = x_ref[0, 0, 0].astype(jnp.float32)             # (Q, P)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)           # (Q, 1)
     B = b_ref[0, 0].astype(jnp.float32)                # (Q, N)
     C = c_ref[0, 0].astype(jnp.float32)                # (Q, N)
     a = -jnp.exp(alog_ref[0].astype(jnp.float32))      # (1, 1)
 
     dA = dt * a                                        # (Q, 1)
-    dA_cum = jnp.cumsum(dA, axis=0)                    # (Q, 1)
-
     q = x.shape[0]
-    seg = dA_cum - dA_cum.reshape(1, q)                # (Q, Q): cum_i - cum_j
     tri = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
            >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
+    # cumsum as a masked row sum (Mosaic has no cumsum lowering)
+    dA_cum = jnp.sum(jnp.where(tri, dA.reshape(1, q), 0.0), axis=1,
+                     keepdims=True)                    # (Q, 1)
+
+    seg = dA_cum - dA_cum.reshape(1, q)                # (Q, Q): cum_i - cum_j
     L = jnp.where(tri, jnp.exp(seg), 0.0)
 
     scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
@@ -48,14 +50,14 @@ def _ssd_chunk_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref,
     w = scores * L * dt.reshape(1, q)                  # weight for column j
     y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)       # (Q,P)
-    y_ref[0, 0, :, 0] = y.astype(y_ref.dtype)
+    y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
     decay_e = jnp.exp(dA_cum[q - 1] - dA_cum)          # (Q,1)
     xw = x * (dt * decay_e)                            # (Q,P)
     st = jax.lax.dot_general(B, xw, (((0,), (0,)), ((), ())),
                              preferred_element_type=jnp.float32)      # (N,P)
     st_ref[0, 0] = st.astype(st_ref.dtype)
-    clf_ref[...] = dA_cum[q - 1].reshape(1, 1).astype(clf_ref.dtype)
+    clf_ref[0, 0] = dA_cum[q - 1].reshape(1, 1).astype(clf_ref.dtype)
 
 
 def ssd_chunk_pallas(x, dt, A_log, B, C, *, interpret: bool = False):
@@ -69,7 +71,11 @@ def ssd_chunk_pallas(x, dt, A_log, B, C, *, interpret: bool = False):
     """
     b, nc, q, h, p = x.shape
     n = B.shape[-1]
-    # dt blocked with trailing singleton head dim -> (Q, 1) tiles in VMEM
+    # head-major tiles: the TPU lowering needs each block's last two dims
+    # to be (8, 128)-aligned or whole, so the head index must not sit in
+    # them — x as (b,nc,h,Q,P), dt as (b,nc,h,Q,1)
+    xh = x.transpose(0, 1, 3, 2, 4)
+    dth = dt.transpose(0, 1, 3, 2)[..., None]
     al = A_log.reshape(h, 1, 1)
 
     grid = (b, nc, h)
@@ -77,24 +83,26 @@ def ssd_chunk_pallas(x, dt, A_log, B, C, *, interpret: bool = False):
         _ssd_chunk_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, q, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
-            pl.BlockSpec((1, 1, q, 1), lambda bi, ci, hi: (bi, ci, 0, hi)),
+            pl.BlockSpec((1, 1, 1, q, p), lambda bi, ci, hi: (bi, ci, hi, 0, 0)),
+            pl.BlockSpec((1, 1, 1, q, 1), lambda bi, ci, hi: (bi, ci, hi, 0, 0)),
             pl.BlockSpec((1, 1, 1), lambda bi, ci, hi: (hi, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda bi, ci, hi: (bi, ci, 0, 0)),
             pl.BlockSpec((1, 1, q, n), lambda bi, ci, hi: (bi, ci, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, q, 1, p), lambda bi, ci, hi: (bi, ci, 0, hi, 0)),
+            pl.BlockSpec((1, 1, 1, q, p), lambda bi, ci, hi: (bi, ci, hi, 0, 0)),
             pl.BlockSpec((1, 1, n, p), lambda bi, ci, hi: (bi, ci * h + hi, 0, 0)),
-            pl.BlockSpec((1, 1), lambda bi, ci, hi: (bi, ci * h + hi)),
+            pl.BlockSpec((1, 1, 1, 1),
+                         lambda bi, ci, hi: (bi, ci * h + hi, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, nc, q, h, p), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc, h, q, p), jnp.float32),
             jax.ShapeDtypeStruct((b, nc * h, n, p), jnp.float32),
-            jax.ShapeDtypeStruct((b, nc * h), jnp.float32),
+            jax.ShapeDtypeStruct((b, nc * h, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x, dt, al, B, C)
+    )(xh, dth, al, B, C)
+    y = y.transpose(0, 1, 3, 2, 4)
     states = st.reshape(b, nc, h, n, p)
     chunk_lf = clf.reshape(b, nc, h)
     return y, states, chunk_lf

@@ -7,14 +7,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.ssm_scan.kernel import ssd_chunk_pallas
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except RuntimeError:
-        return False
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
@@ -22,7 +16,7 @@ def ssd_scan(x, dt, A_log, B, C, *, chunk: int = 256,
              interpret: bool | None = None):
     """x: (b,s,h,p); dt: (b,s,h); A_log: (h,); B,C: (b,s,n) -> (b,s,h,p)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     b, s, h, p = x.shape
     n = B.shape[-1]
     q = min(chunk, s)

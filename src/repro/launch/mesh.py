@@ -17,19 +17,28 @@ parallelism; ``model`` is tensor/expert parallelism over ICI.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes.  The sharding rules annotate
+    activations with ``with_sharding_constraint``, which JAX's default
+    ``Explicit`` axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(n_devices: int | None = None, *, model: int = 2):
     """Small mesh over however many (host) devices exist — for tests."""
     n = n_devices or len(jax.devices())
     data = max(n // model, 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants (the roofline denominators)

@@ -267,6 +267,8 @@ def main() -> None:
     g.set_defaults(fn=run_generate)
 
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     args.fn(args)
 
 

@@ -38,6 +38,9 @@ def main() -> None:
                               "=512").strip()
 
     import jax
+
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.distributed:
         jax.distributed.initialize()
 

@@ -87,7 +87,11 @@ class Trainer:
                 data_state = DataState.from_dict(ds)
             start_step = step
         if params is None:
-            params, opt_state = self.init_state()
+            # initialise in place on the mesh: an eager init would build the
+            # whole unsharded state on one device before the first step
+            params, opt_state = jax.jit(
+                self.init_state,
+                out_shardings=self.bundle.in_shardings[:2])()
 
         host_batch = self.shape.global_batch // tcfg.n_hosts
         queue = BatchQueue(self.producer, batch=host_batch,

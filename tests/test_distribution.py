@@ -30,7 +30,8 @@ def test_pipeline_parallel_matches_sequential():
     import jax, jax.numpy as jnp, numpy as np
     from repro.train.pipeline import (pipeline_apply, split_microbatches,
                                       bubble_fraction)
-    mesh = jax.make_mesh((4,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("stage",))
     S, M, B, D = 4, 8, 16, 32
     rng = jax.random.PRNGKey(0)
     ws = jax.random.normal(rng, (S, D, D)) * 0.3
@@ -58,7 +59,8 @@ def test_pipeline_parallel_gradients():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.train.pipeline import pipeline_loss, split_microbatches
-    mesh = jax.make_mesh((4,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("stage",))
     S, M, B, D = 4, 4, 8, 16
     rng = jax.random.PRNGKey(0)
     params = {"w": jax.random.normal(rng, (S, D, D)) * 0.3}
@@ -100,7 +102,8 @@ def test_train_step_lowers_on_small_mesh():
     from repro.models.model import build_model, reduce_config
     from repro.optim import make_optimizer
     from repro.train.step import build_step, lower_step
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = reduce_config(ARCHS["llama3.2-3b"], d_model=64, n_heads=4,
                         n_kv_heads=2, vocab=512)
     model = build_model(cfg)
@@ -122,7 +125,8 @@ def test_train_step_executes_on_small_mesh():
     from repro.models.model import build_model, reduce_config
     from repro.optim import make_optimizer
     from repro.train.step import make_train_step
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = reduce_config(ARCHS["granite-moe-1b-a400m"], d_model=64,
                         n_heads=4, n_kv_heads=2, vocab=512)
     model = build_model(cfg)
@@ -154,7 +158,8 @@ def test_decode_step_lowers_on_small_mesh():
     from repro.configs.base import ShapeConfig
     from repro.models.model import build_model, reduce_config
     from repro.train.step import build_step, lower_step
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     for arch in ("zamba2-7b", "xlstm-1.3b", "phi4-mini-3.8b"):
         cfg = reduce_config(ARCHS[arch], d_model=64, vocab=512)
         model = build_model(cfg)
@@ -173,7 +178,8 @@ def test_compressed_pod_allreduce():
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim.compression import compressed_psum_pod, init_residual
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("pod", "data"))
     g_global = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 32))
     grads = {"w": g_global}
     res = {"w": jnp.zeros((64, 32))}

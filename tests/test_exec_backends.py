@@ -196,6 +196,31 @@ def test_engines_expose_the_transfer_seam():
     np.testing.assert_array_equal(np.asarray(dev["t"]), np.arange(16.0))
 
 
+def test_device_stream_swaps_to_pinned_host():
+    """A swap-out really leaves device memory, and the prefetch brings the
+    bytes back into it."""
+    eng = DeviceStreamEngine()
+    assert eng.host_memory_kind == "pinned_host"
+    a = jnp.arange(32.0)
+    h = eng.swap_out("X:t", {"t": a}, a.nbytes)
+    assert h["t"].sharding.memory_kind == "pinned_host"
+    back = eng.swap_in("X:t", h, a.nbytes)
+    eng.fence("X:t", SwapExecStats())
+    assert back["t"].sharding.memory_kind == "device"
+    np.testing.assert_array_equal(np.asarray(back["t"]), np.arange(32.0))
+
+
+def test_device_without_host_memory_has_no_swap_engine():
+    """No pinned_host space means no host tier: the engine refuses rather
+    than build a "host" sharding that is really device memory."""
+    class DeviceOnly:
+        def addressable_memories(self):
+            return [jax.devices()[0].default_memory()]
+
+    with pytest.raises(RuntimeError, match="pinned_host"):
+        DeviceStreamEngine(DeviceOnly())
+
+
 # ---------------------------------------------------------------------------
 # ExecutionSchedule.counts()/transfers() edge cases (direct unit tests)
 # ---------------------------------------------------------------------------

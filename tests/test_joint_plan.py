@@ -188,28 +188,10 @@ def test_model_config_hardware_knobs_flow_through_facade():
 
 
 # ---------------------------------------------------------------------------
-# Offload lowering degradation is loud and reported
+# Offload lowering is native and reported
 # ---------------------------------------------------------------------------
 
-def test_offload_policy_fallback_warns_and_is_reported(monkeypatch):
-    import jax
-    from repro.core.offload import offload_policy
-    monkeypatch.delattr(jax.checkpoint_policies,
-                        "save_and_offload_only_these_names")
-    assert offload_lowering() == "fallback_save"
-    with pytest.warns(RuntimeWarning, match="fallback_save"):
-        assert offload_policy(["mlp_hidden"], saved=["attn_out"]) is not None
-    cp = compile_plan(ARCHS["llama3.2-3b"], MemoryPlanConfig(
-        remat=True, remat_budget_bytes=1 << 20, offload=True, **HW),
-        batch_tokens=2048)
-    assert cp.report()["offload_lowering"] == "fallback_save"
-
-
 def test_offload_lowering_native_on_this_jax():
-    import jax
-    if not hasattr(jax.checkpoint_policies,
-                   "save_and_offload_only_these_names"):
-        pytest.skip("installed JAX lacks the offload policy")
     assert offload_lowering() == "native"
     cp = compile_plan(ARCHS["llama3.2-3b"], MemoryPlanConfig(
         remat=True, remat_budget_bytes=1 << 20, offload=True, **HW),

@@ -195,3 +195,42 @@ def test_fused_swiglu_matches_ref(case, dtype):
     ref = swiglu_ref(x, wg, wu)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **_tol(dtype))
+
+
+# ---------------------------------------------------------------------------
+# interpret mode: only on the CPU, never as a silent fallback
+# ---------------------------------------------------------------------------
+
+def _kernel_calls():
+    # shapes no other test traces, so each wrapper re-traces and asks
+    # for the backend again
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.kernels.fused_swiglu.ops import fused_swiglu
+    k = jax.random.PRNGKey(7)
+    x4 = jax.random.normal(k, (1, 24, 2, 8))
+    xs = jax.random.normal(k, (1, 24, 3, 8))
+    g = jax.random.normal(k, (1, 24, 3))
+    return {
+        "flash_attention": lambda: flash_attention(
+            x4, x4, x4, causal=True, block_q=8, block_kv=8),
+        "fused_swiglu": lambda: fused_swiglu(
+            jnp.ones((24, 40)), jnp.ones((40, 56)), jnp.ones((40, 56)),
+            block_m=8, block_f=8, block_k=8),
+        "ssd_scan": lambda: ssd_scan(
+            xs, jnp.abs(g), jnp.zeros((3,)), xs[..., 0, :], xs[..., 1, :],
+            chunk=8),
+        "mlstm_scan": lambda: mlstm_scan(xs, xs, xs, g, g, chunk=8),
+    }
+
+
+@pytest.mark.parametrize("name", ["flash_attention", "fused_swiglu",
+                                  "ssd_scan", "mlstm_scan"])
+def test_kernel_wrappers_refuse_non_tpu_backends(monkeypatch, name):
+    from repro.kernels import interpret_mode
+    call = _kernel_calls()[name]
+    assert interpret_mode() is True            # the CPU interprets
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        call()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False

@@ -84,7 +84,8 @@ def test_probe_linearity_small():
     from repro.configs.base import ShapeConfig
     from repro.launch.probe import _lower_and_cost, probe_config
     from repro.models.model import reduce_config
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2), ("data", "model"))
     cfg = reduce_config(ARCHS["llama3.2-3b"], d_model=64, n_heads=4,
                         n_kv_heads=2, vocab=512)
     shape = ShapeConfig("t", 64, 4, "train")
