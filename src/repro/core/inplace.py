@@ -41,11 +41,16 @@ def _tanh_deriv(y):
 
 
 def _relu_fwd(x):
-    return jnp.maximum(x, 0.0)
+    # jax.nn.relu, not jnp.maximum(x, 0): under autodiff jnp.maximum gives
+    # a tie at x == 0 half the cotangent, where the output-based derivative
+    # below gives 0.  A conv output can be exactly 0 (seen on a TPU at
+    # float32 matmul precision), and jax.grad must then agree with the
+    # layer-basis backward.
+    return jax.nn.relu(x)
 
 
 def _relu_deriv(y):
-    # y > 0 exactly where x > 0 (ties at 0 have zero derivative anyway)
+    # y > 0 exactly where x > 0; the derivative at x == 0 is 0
     return (y > 0.0).astype(y.dtype)
 
 

@@ -72,6 +72,25 @@ def test_planned_grads_match_autodiff(name, int_in):
     _tree_allclose(grads_p, grads_r)
 
 
+def test_relu_tie_at_zero_matches_autodiff():
+    """A pre-activation of exactly 0 gets derivative 0 on both sides."""
+    from repro.core.graph import LayerGraph, LayerNode, compile_graph
+    g = compile_graph(LayerGraph([
+        LayerNode("fc0", "linear", ["__input__"],
+                  {"in_features": 4, "out_features": 3, "activation": "relu"}),
+        LayerNode("fc1", "linear", ["fc0"],
+                  {"in_features": 3, "out_features": 2}),
+        LayerNode("loss", "loss_mse", ["fc1"]),
+    ], (4,), (2,), "relu_tie"))
+    params = init_params(g, jax.random.PRNGKey(0))
+    params["fc0"]["b"] = jnp.zeros_like(params["fc0"]["b"])
+    x, y = _make_batch(g, 4, jax.random.PRNGKey(1))
+    x = x.at[0].set(0.0)            # row 0: every fc0 pre-activation is 0
+    _, grads_p = planned_loss_and_grads(g, params, x, y)
+    _, grads_r = reference_loss_and_grads(g, params, x, y)
+    _tree_allclose(grads_p, grads_r)
+
+
 def test_unrolled_tacotron_grads_match_scan_autodiff():
     """E-shared unrolled LSTM: accumulated grads == autodiff over the whole
     unrolled graph (weights tied)."""
