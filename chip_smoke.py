@@ -77,21 +77,6 @@ def check(cond: bool, msg: str) -> None:
         raise SmokeFailure(msg)
 
 
-class CompileCounter:
-    """Counts executables JAX builds (one event per jit-cache miss)."""
-
-    EVENT = "/jax/core/compile/backend_compile_duration"
-
-    def __init__(self) -> None:
-        import jax
-        self.n = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
-
-    def _on_event(self, event: str, duration: float, **kwargs) -> None:
-        if event == self.EVENT:
-            self.n += 1
-
-
 # ---------------------------------------------------------------------------
 # 1. device check
 # ---------------------------------------------------------------------------
@@ -159,14 +144,14 @@ def train_steps(cp, graph, executor: str, steps: int, counter):
     for step in range(steps):
         x, y = dummy_batch(graph, cp.batch, seed=SEED + 1 + step)
         jax.block_until_ready((x, y))
-        compiles = counter.n
+        compiles = counter.programs
         t0 = time.perf_counter()
         loss, grads, stats = cp.loss_and_grads(params, x, y,
                                                executor=executor)
         new_params = offloaded_update(runtime, params, grads, opt_stats)
         jax.block_until_ready((loss, grads, new_params))
         wall = time.perf_counter() - t0
-        compiles = counter.n - compiles
+        compiles = counter.programs - compiles
         _, ref_grads = reference_loss_and_grads(graph, params, x, y)
         gate, max_abs = grad_gap(grads, ref_grads)
         records.append({
@@ -470,7 +455,10 @@ def main() -> None:
     dev = device_check(args.chips)
     from repro.runtime.compile_cache import enable_compile_cache
     log(f"compile cache: {enable_compile_cache()}")
-    counter = CompileCounter()
+    # programs JAX builds (one per jit-cache miss), counted by the package
+    from repro.runtime.trace import compile_counter
+    counter = compile_counter()
+    programs0, seconds0 = counter.programs, counter.seconds
     t0 = time.perf_counter()
     if args.chips == 4:
         phase_sharded(args.chips)
@@ -478,7 +466,8 @@ def main() -> None:
         phase_train(counter)
         phase_serve()
     log(f"phases passed in {time.perf_counter() - t0:.1f} s, "
-        f"{counter.n} programs compiled")
+        f"{counter.programs - programs0} programs compiled in "
+        f"{counter.seconds - seconds0:.1f} s")
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}), flush=True)

@@ -65,6 +65,7 @@ from repro.core.exec.store import (ActivationStore, DeviceStreamEngine,
 from repro.core.execution_order import OrderedTensors, compute_execution_order
 from repro.core.graph import LOSS_KINDS, WEIGHTED_KINDS, LayerGraph
 from repro.core.offload import OffloadSchedule
+from repro.runtime.trace import span
 
 
 @runtime_checkable
@@ -355,7 +356,8 @@ class ScheduleCursor:
         reference.  The cursor yields no result."""
         if self._finished:
             return
-        self.engine.drain(self.stats)
+        with span("exec.drain"):
+            self.engine.drain(self.stats)
         self.store.device.clear()
         self.store.host.clear()
         self.store.alive.clear()
@@ -402,19 +404,21 @@ class ScheduleCursor:
                 stats.peak_inflight_prefetch, self._inflight)
             self._replayed.append(op)
         elif isinstance(op, Compute):
-            # prefetches issued at earlier phases complete by their read
-            # EO: retire their double-buffer slots at the phase boundary,
-            # and fence optimizer slots whose read EO has arrived
-            if op.eo > self._retired_eo:
-                for eo in list(self._done_at):
-                    if eo <= op.eo:
-                        self._inflight -= self._done_at.pop(eo)
-                for eo in list(self._opt_fence_at):
-                    if eo <= op.eo:
-                        for owner in self._opt_fence_at.pop(eo):
-                            self.engine.opt_fence(owner, stats)
-                self._retired_eo = op.eo
-            self.env.step(op)
+            with span("exec.compute", layer=op.layer, kind=op.kind):
+                # prefetches issued at earlier phases complete by their
+                # read EO: retire their double-buffer slots at the phase
+                # boundary, and fence optimizer slots whose read EO has
+                # arrived
+                if op.eo > self._retired_eo:
+                    for eo in list(self._done_at):
+                        if eo <= op.eo:
+                            self._inflight -= self._done_at.pop(eo)
+                    for eo in list(self._opt_fence_at):
+                        if eo <= op.eo:
+                            for owner in self._opt_fence_at.pop(eo):
+                                self.engine.opt_fence(owner, stats)
+                    self._retired_eo = op.eo
+                self.env.step(op)
             self._replayed.append(op)
         elif isinstance(op, SwapOut):
             if op.tensor in store.alive:
@@ -432,7 +436,8 @@ class ScheduleCursor:
     def _finish(self) -> None:
         t0 = self._clock()
         stats, plan = self.stats, self.plan
-        self.engine.drain(stats)
+        with span("exec.drain"):
+            self.engine.drain(stats)
         stats.wall_time_s += self._clock() - t0
         stats.hbm_high_water = self.hbm.high_water
         stats.host_high_water = self.store.host_pool.high_water
@@ -524,11 +529,12 @@ class _ReplayBackend:
             ordered: Optional[OrderedTensors] = None,
             plan=None, lowered=None, mask=None,
             engine: Optional[TransferEngine] = None):
-        cursor = self.start(graph, params, x, label, schedule=schedule,
-                            ordered=ordered, plan=plan, lowered=lowered,
-                            mask=mask, engine=engine)
-        while cursor.advance():
-            pass
+        with span("exec.step", backend=self.name):
+            cursor = self.start(graph, params, x, label, schedule=schedule,
+                                ordered=ordered, plan=plan, lowered=lowered,
+                                mask=mask, engine=engine)
+            while cursor.advance():
+                pass
         return cursor.result()
 
     def _finalize_stats(self, stats: SwapExecStats,
@@ -628,10 +634,11 @@ class AsyncDeviceBackend(_ReplayBackend):
             # <= 1.0 means the stream never held more than planned
             "inflight_vs_planned": (s.inflight_high_water / planned
                                     if planned else None),
-            # measured bus-time split: seconds the activation DMAs ran
-            # hidden under dispatched compute vs seconds consumer fences
-            # actually blocked — and the same split for the optimizer
-            # lane, whose OptPrefetch H2D now streams on the real engine
+            # fence timings, summed over transfers: per transfer the
+            # seconds from issue to its consumer fence (hidden) and the
+            # seconds that fence blocked (exposed), for the activation
+            # and the optimizer lane.  Overlapping transfers each add
+            # their own interval: these are not bus time
             "hidden_dma_s": s.hidden_dma_s,
             "exposed_dma_s": s.exposed_dma_s,
             "opt_hidden_dma_s": s.opt_hidden_dma_s,
@@ -769,6 +776,13 @@ class JitBlocksBackend(AsyncDeviceBackend):
             schedule: OffloadSchedule,
             ordered: Optional[OrderedTensors] = None,
             plan=None, lowered=None, mask=None):
+        with span("exec.step", backend=self.name):
+            return self._run(graph, params, x, label, schedule=schedule,
+                             ordered=ordered, plan=plan, lowered=lowered,
+                             mask=mask)
+
+    def _run(self, graph, params, x, label, *, schedule, ordered, plan,
+             lowered, mask):
         import time as _time
 
         from repro.core.plan import (Compute, Free, OptPrefetch, OptSwapOut,
@@ -844,8 +858,9 @@ class JitBlocksBackend(AsyncDeviceBackend):
                         if eo <= last_eo:
                             inflight -= done_at.pop(eo)
                     retired_eo = last_eo
-                self._exec_block(block, ops, graph, store, env, stats,
-                                 params, label, mask, cache)
+                with span("exec.block", block=block.index):
+                    self._exec_block(block, ops, graph, store, env, stats,
+                                     params, label, mask, cache)
                 stats.dispatch_calls += 1
                 for ci in block.compute_indices:
                     replayed.append(ops[ci])
@@ -894,7 +909,8 @@ class JitBlocksBackend(AsyncDeviceBackend):
                         if eo <= op.eo:
                             inflight -= done_at.pop(eo)
                     retired_eo = op.eo
-                env.step(op)
+                with span("exec.compute", layer=op.layer, kind=op.kind):
+                    env.step(op)
                 replayed.append(op)
                 stats.dispatch_calls += 1
             elif isinstance(op, SwapOut):
@@ -909,7 +925,8 @@ class JitBlocksBackend(AsyncDeviceBackend):
                 stats.dispatch_calls += 1
             sanitize_step(op, op_index, cross=True)
 
-        engine.drain(stats)
+        with span("exec.drain"):
+            engine.drain(stats)
         stats.wall_time_s = _time.perf_counter() - t_run0
         stats.hbm_high_water = hbm.high_water
         stats.host_high_water = store.host_pool.high_water

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core.execution_order import OrderedTensors
 from repro.core.lifespan import CreateMode
+from repro.runtime.trace import span
 
 
 @dataclasses.dataclass
@@ -79,22 +80,30 @@ class SwapExecStats:
     opt_dma_bytes: int = 0
     opt_compressed_bytes: int = 0  # host-side bytes after quantization
     opt_device_high_water: int = 0 # peak resident optimizer working bytes
-    # ---- measured bus-time split (device-stream engines only) ----
-    # activation lane: seconds each prefetch spent in flight before its
-    # consumer fence (hidden behind dispatched compute) vs seconds the
-    # fence actually blocked (exposed on the critical path)
+    # ---- fence timings (device-stream engines only) ----
+    # activation lane, summed over transfers: per prefetch, the host
+    # seconds from its issue to its consumer's fence (hidden_dma_s) and
+    # the seconds that fence blocked (exposed_dma_s).  Transfers in flight
+    # together each add their own issue-to-fence interval, so
+    # hidden_dma_s is not bus time and can exceed the step's wall time;
+    # the measured exposed wait is the exec.fence span of a profiler trace
+    # (repro.runtime.trace)
     hidden_dma_s: float = 0.0
     exposed_dma_s: float = 0.0
-    # optimizer lane, same split: OptPrefetch H2D issued at its scheduled
-    # EO and fenced at the first Compute of its read EO
+    # optimizer lane, the same sums: OptPrefetch H2D issued at its
+    # scheduled EO and fenced at the first Compute of its read EO (the
+    # exec.opt_fence span)
     opt_hidden_dma_s: float = 0.0
     opt_exposed_dma_s: float = 0.0
     opt_fences: int = 0            # optimizer-lane consumer fences
     opt_stalled_fences: int = 0    # opt fences that actually had to block
     opt_inflight_high_water: int = 0  # peak issued-but-unfenced opt bytes
-    # portion of hidden_dma_s that elapsed while *another* session held
-    # the compute slot — credited by the phase-interleaved StepScheduler
-    # (repro.serve.scheduler); 0.0 for single-session runs
+    # the host seconds of other sessions' phase advances during which this
+    # session had a transfer in flight, credited by the phase-interleaved
+    # StepScheduler (repro.serve.scheduler): each advance credits its
+    # time to every other session with a transfer in flight, so summed
+    # over sessions it can exceed the wall time; not bus time.  0.0 for
+    # single-session runs
     cross_hidden_dma_s: float = 0.0
 
 
@@ -371,14 +380,15 @@ class DeviceStreamEngine:
         if entry is None:
             return
         nbytes, arrays, issued, ready_at = entry
-        t0 = self._clock()
-        ready = all(a.is_ready() for a in arrays
-                    if hasattr(a, "is_ready")) and t0 >= ready_at
-        jax.block_until_ready(arrays)
-        if ready_at > 0.0:
-            left = ready_at - self._clock()
-            if left > 0:
-                self._sleep(left)        # emulated bus stall -> exposed
+        with span("exec.fence", owner=owner):
+            t0 = self._clock()
+            ready = all(a.is_ready() for a in arrays
+                        if hasattr(a, "is_ready")) and t0 >= ready_at
+            jax.block_until_ready(arrays)
+            if ready_at > 0.0:
+                left = ready_at - self._clock()
+                if left > 0:
+                    self._sleep(left)    # emulated bus stall -> exposed
         self.inflight_bytes -= nbytes
         self.fences += 1
         stats.fences += 1
@@ -395,14 +405,15 @@ class DeviceStreamEngine:
         if entry is None:
             return
         host_nbytes, arr, issued, ready_at = entry
-        t0 = self._clock()
-        ready = (arr.is_ready() if hasattr(arr, "is_ready") else True) \
-            and t0 >= ready_at
-        jax.block_until_ready(arr)
-        if ready_at > 0.0:
-            left = ready_at - self._clock()
-            if left > 0:
-                self._sleep(left)
+        with span("exec.opt_fence", owner=owner):
+            t0 = self._clock()
+            ready = (arr.is_ready() if hasattr(arr, "is_ready")
+                     else True) and t0 >= ready_at
+            jax.block_until_ready(arr)
+            if ready_at > 0.0:
+                left = ready_at - self._clock()
+                if left > 0:
+                    self._sleep(left)
         self.opt_inflight_bytes -= host_nbytes
         stats.opt_fences += 1
         stats.opt_hidden_dma_s += t0 - issued
@@ -589,7 +600,8 @@ class ActivationStore:
         for m in self.members.get(owner, ()):
             if m in self.device:
                 moved[m] = self.device.pop(m)
-        self.host.update(self.engine.swap_out(owner, moved, nbytes))
+        with span("exec.swap_out", owner=owner, nbytes=nbytes):
+            self.host.update(self.engine.swap_out(owner, moved, nbytes))
         self.alive.discard(owner)
         self.hbm.free(nbytes)
         self.host_pool.alloc(nbytes)
@@ -602,7 +614,8 @@ class ActivationStore:
         for m in self.members.get(owner, ()):
             if m in self.host:
                 moved[m] = self.host.pop(m)
-        self.device.update(self.engine.swap_in(owner, moved, nbytes))
+        with span("exec.swap_in", owner=owner, nbytes=nbytes):
+            self.device.update(self.engine.swap_in(owner, moved, nbytes))
         self.alive.add(owner)
         self.hbm.alloc(nbytes)
         self.host_pool.free(nbytes)

@@ -62,6 +62,7 @@ from repro.core.execution_order import OrderedTensors
 from repro.core.graph import WEIGHTED_KINDS, LayerGraph
 from repro.core.lifespan import CreateMode, Lifespan, TensorSpec
 from repro.core.planner import Plan, _SpecSet, _align, get_planner
+from repro.runtime.trace import span
 
 _HOST = "@host"
 
@@ -440,28 +441,38 @@ def offloaded_update(runtime: OptimRuntime, params, grads, stats=None):
 
     from repro.core.exec.store import commit
 
-    # fresh init params are uncommitted, later ones are committed outputs
-    params, grads = commit((params, grads))
     runtime.count += 1
-    t = float(runtime.count)
-    c1 = 1.0 - runtime.b1 ** t
-    c2 = 1.0 - runtime.b2 ** t
-    new_params = {ln: dict(entry) for ln, entry in params.items()}
-    for s in sorted(runtime.plan.slots, key=lambda s: s.prefetch_eo):
-        layer = s.layer
-        if layer not in grads:
-            continue
-        flat = runtime.prefetch(layer, stats)
-        ms, vs = runtime.unpack(layer, flat)
-        for wname, _, _ in runtime.layouts[layer]:
-            g = grads[layer][wname].astype(jnp.float32)
-            p = params[layer][wname]
-            m = runtime.b1 * ms[wname] + (1 - runtime.b1) * g
-            v = runtime.b2 * vs[wname] + (1 - runtime.b2) * g * g
-            upd = (m / c1) / (jnp.sqrt(v / c2) + runtime.eps)
-            new_params[layer][wname] = (
-                p - runtime.lr * (upd + runtime.weight_decay
-                                  * p.astype(jnp.float32))).astype(p.dtype)
-            ms[wname], vs[wname] = m, v
-        runtime.swap_out(layer, runtime.pack(layer, ms, vs), stats)
+    step = runtime.count
+    with span("optim.update", step=step):
+        # fresh init params are uncommitted, later ones are committed
+        # outputs
+        params, grads = commit((params, grads))
+        t = float(step)
+        c1 = 1.0 - runtime.b1 ** t
+        c2 = 1.0 - runtime.b2 ** t
+        new_params = {ln: dict(entry) for ln, entry in params.items()}
+        for s in sorted(runtime.plan.slots, key=lambda s: s.prefetch_eo):
+            layer = s.layer
+            if layer not in grads:
+                continue
+            with span("optim.decode", step=step, layer=layer):
+                flat = runtime.prefetch(layer, stats)
+            with span("optim.unpack", step=step, layer=layer):
+                ms, vs = runtime.unpack(layer, flat)
+            with span("optim.adamw", step=step, layer=layer):
+                for wname, _, _ in runtime.layouts[layer]:
+                    g = grads[layer][wname].astype(jnp.float32)
+                    p = params[layer][wname]
+                    m = runtime.b1 * ms[wname] + (1 - runtime.b1) * g
+                    v = runtime.b2 * vs[wname] + (1 - runtime.b2) * g * g
+                    upd = (m / c1) / (jnp.sqrt(v / c2) + runtime.eps)
+                    new_params[layer][wname] = (
+                        p - runtime.lr * (upd + runtime.weight_decay
+                                          * p.astype(jnp.float32))
+                    ).astype(p.dtype)
+                    ms[wname], vs[wname] = m, v
+            with span("optim.pack", step=step, layer=layer):
+                flat = runtime.pack(layer, ms, vs)
+            with span("optim.encode", step=step, layer=layer):
+                runtime.swap_out(layer, flat, stats)
     return new_params

@@ -140,6 +140,7 @@ from repro.core.planner import (Plan, SwapAwarePlan, get_planner,
                                 plan_memory_swapped)
 from repro.core.remat_policy import (RematPlan, plan_joint_policy,
                                      transformer_intermediates)
+from repro.runtime.trace import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -781,7 +782,8 @@ def _apply_verify(cp: CompiledMemoryPlan) -> CompiledMemoryPlan:
     if cp.config.verify == "off":
         return cp
     from repro.core import verify as _verify
-    report = _verify.verify_plan(cp)
+    with span("plan.verify"):
+        report = _verify.verify_plan(cp)
     cp.verify_report = report
     if report.ok:
         if cp.lowered is not None:
@@ -817,9 +819,10 @@ def compile_plan(graph_or_model, config: Optional[MemoryPlanConfig] = None,
     :class:`MemoryPlanConfig()`.
     """
     config = config or MemoryPlanConfig()
-    if isinstance(graph_or_model, LayerGraph):
-        return _compile_graph_plan(graph_or_model, config, batch)
-    return _compile_model_plan(graph_or_model, config, batch_tokens)
+    with span("plan.compile", batch=batch):
+        if isinstance(graph_or_model, LayerGraph):
+            return _compile_graph_plan(graph_or_model, config, batch)
+        return _compile_model_plan(graph_or_model, config, batch_tokens)
 
 
 def _compile_graph_plan(graph: LayerGraph, config: MemoryPlanConfig,

@@ -12,10 +12,15 @@ defines: all of a phase's DMA has been issued but need not be fenced
 until a later phase computes — so while session A's ``SwapOut`` /
 ``Prefetch`` / ``OptPrefetch`` copies are on the bus, the scheduler
 advances session B's ``Compute`` phases, and A's DMA hides under B's
-compute.  That cross-session overlap is measured, not asserted: every
-second one session spends computing while another session's transfers
-are in flight is credited to the waiting session's
-``SwapExecStats.cross_hidden_dma_s``.
+compute.  The scheduler credits every host second one session spends in
+a phase advance to each other session that has a transfer in flight
+meanwhile (``SwapExecStats.cross_hidden_dma_s``).  That is an upper
+bound on the overlap, not bus time: one advance is credited to every
+waiting session at once, so the sum over sessions can exceed the wave's
+wall time.  The wave's measured exposed transfer wait is the
+``exec.fence`` / ``exec.opt_fence`` spans of a profiler trace
+(:mod:`repro.runtime.trace`), nested in the ``sched.advance`` span of the
+session that waited.
 
 Safety before speed, in the house style (prove-then-run):
 
@@ -50,6 +55,7 @@ from repro.core.exec import (AsyncDeviceBackend, DeviceStreamEngine,
 from repro.core.verify import (is_verified, schedules_equivalent,
                                verify_interleaving)
 from repro.runtime.fault import FaultInjector
+from repro.runtime.trace import span
 from repro.serve.admission import ServeStats
 
 
@@ -224,7 +230,8 @@ class StepScheduler:
         t_wave0 = time.perf_counter()
 
         def open_live(w: SessionWork, start_after: int = 0) -> None:
-            live = self._open(w)
+            with span("sched.open", user=w.user, arrival=w.arrival):
+                live = self._open(w)
             live.start_after = start_after
             active.append(live)
             if w.enqueued_at is not None and stats is not None:
@@ -261,12 +268,14 @@ class StepScheduler:
         def finish(live: _Live, status: str, reason: str = "") -> None:
             w = live.work
             if status == "ok":
-                loss, grads, st = live.cursor.result()
-                self._prove_replay(live, proved)
-                outcomes[w.arrival] = StepOutcome(
-                    user=w.user, arrival=w.arrival, qos=w.qos, status="ok",
-                    loss=float(loss), grads=grads, stats=st,
-                    queue_wait_s=getattr(live, "queue_wait_s", 0.0))
+                with span("sched.finish", user=w.user, arrival=w.arrival):
+                    loss, grads, st = live.cursor.result()
+                    self._prove_replay(live, proved)
+                    outcomes[w.arrival] = StepOutcome(
+                        user=w.user, arrival=w.arrival, qos=w.qos,
+                        status="ok", loss=float(loss), grads=grads,
+                        stats=st,
+                        queue_wait_s=getattr(live, "queue_wait_s", 0.0))
             else:
                 outcomes[w.arrival] = StepOutcome(
                     user=w.user, arrival=w.arrival, qos=w.qos,
@@ -314,7 +323,9 @@ class StepScheduler:
                     continue
                 credits = max(1, int(live.work.weight))
                 for i in range(credits):
-                    more = live.cursor.advance()
+                    with span("sched.advance", user=w.user,
+                              arrival=w.arrival):
+                        more = live.cursor.advance()
                     phase_advances += 1
                     advanced_any = True
                     dt = live.cursor.last_advance_s
@@ -340,6 +351,9 @@ class StepScheduler:
         done = [outcomes[w.arrival]
                 for w in sorted(all_works, key=lambda w: w.arrival)]
         ok = [o for o in done if o.ok]
+        # the *_dma_s entries are sums over sessions of per-transfer (and,
+        # for cross_hidden_dma_s, per-advance) intervals that overlap one
+        # another: not bus time, and they can exceed wall_time_s
         agg = {
             "sessions": len(all_works),
             "completed": len(ok),

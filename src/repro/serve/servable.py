@@ -23,6 +23,7 @@ from repro.core import CompiledMemoryPlan
 from repro.core.exec.layers import init_params
 from repro.core.exec.store import SwapExecStats
 from repro.core.graph import WEIGHTED_KINDS, LayerGraph
+from repro.runtime.trace import span
 
 Params = Dict[str, Dict[str, jax.Array]]
 
@@ -104,7 +105,8 @@ class ServablePersonalizer:
         replay."""
         loss, grads, stats = cp.loss_and_grads(
             self.merged_params(sess), x, y, mask=mask, engine=engine)
-        self.apply_update(sess, grads)
+        with span("serve.apply_update", user=sess.user):
+            self.apply_update(sess, grads)
         return float(loss), stats
 
     def apply_update(self, sess: Session, grads: Params) -> None:

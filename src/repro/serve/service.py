@@ -53,6 +53,7 @@ import jax
 from repro.core import (ArenaBudgetError, MemoryPlanConfig, compile_plan)
 from repro.core.graph import LayerGraph
 from repro.runtime.fault import FaultInjector
+from repro.runtime.trace import span
 from repro.serve.admission import (AdmissionController, QosClass, ServeStats)
 from repro.serve.buckets import (PlanCache, choose_bucket, dummy_batch,
                                  pad_to_bucket)
@@ -359,7 +360,8 @@ class PersonalizationService:
         Under emulated-bus pacing (``bus_gbps``) this path pays every
         transfer's bus time synchronously — a blocking engine exposes the
         full cost the interleaved scheduler exists to hide."""
-        prepared = self._prepare(req)
+        with span("serve.prepare", user=req.user, arrival=req.arrival):
+            prepared = self._prepare(req)
         if isinstance(prepared, StepResult):
             return prepared
         sess, cp, bucket, xp, yp, mask, qos, queue_wait_s = prepared
@@ -420,7 +422,9 @@ class PersonalizationService:
             q = pending.get(user)
             while q:
                 req = q.popleft()
-                prepared = self._prepare(req)
+                with span("serve.prepare", user=req.user,
+                          arrival=req.arrival):
+                    prepared = self._prepare(req)
                 if isinstance(prepared, StepResult):
                     req.result = prepared
                     done.append((req.arrival, prepared))
@@ -451,7 +455,9 @@ class PersonalizationService:
                               else " (no reservation held)"),
                     qos=oc.qos, queue_wait_s=oc.queue_wait_s)
             else:
-                self.servable.apply_update(sess, oc.grads)
+                with span("serve.apply_update", user=oc.user,
+                          arrival=oc.arrival):
+                    self.servable.apply_update(sess, oc.grads)
                 req.result = self._complete(
                     oc.user, sess, bucket, oc.loss, oc.stats, oc.qos,
                     oc.queue_wait_s)
